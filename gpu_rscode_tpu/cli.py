@@ -633,6 +633,9 @@ def _fail(msg: str) -> "int":
 
 
 def main(argv: list[str] | None = None) -> int:
+    from .utils.backend import configure_compile_cache
+
+    configure_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     if argv and argv[0] == "stats":
         return _stats_main(argv[1:])

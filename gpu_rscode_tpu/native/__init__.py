@@ -1,7 +1,9 @@
 """ctypes bindings for the native host runtime (rs_native.cpp).
 
-The shared library is built on first use with g++ (cached next to the
-source; rebuilt when the source is newer).  Every entry point has a NumPy
+The shared library is built on first use with g++, next to the source,
+under a name keyed on the source's hash, the build flags and the host's
+CPU flags: a ``-march=native`` build from another host or another
+revision of the source is never loaded.  Every entry point has a NumPy
 fallback so the framework works on machines without a toolchain — the
 native path is a performance feature, the Python path is the contract.
 
@@ -12,7 +14,9 @@ inverter (cpu-decode.c), staging copies (encode.cu:389-398).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 import time
@@ -21,8 +25,9 @@ import numpy as np
 
 from ..obs import metrics as _obs_metrics
 
-_SRC = os.path.join(os.path.dirname(__file__), "rs_native.cpp")
-_SO = os.path.join(os.path.dirname(__file__), "librs_native.so")
+_DIR = os.path.dirname(__file__)
+_SRC = os.path.join(_DIR, "rs_native.cpp")
+_CXXFLAGS = ("-O3", "-march=native", "-shared", "-fPIC", "-std=c++17")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _build_failed = False
@@ -46,21 +51,40 @@ def _count_io(direction: str, call: str, nbytes: int, seconds: float) -> None:
     ).labels(call=call).inc(seconds)
 
 
-def _build() -> str:
+def _host_cpu_flags() -> str:
+    """The CPU feature flags ``-march=native`` compiles against."""
+    try:
+        with open("/proc/cpuinfo") as fp:
+            for line in fp:
+                if line.startswith(("flags", "Features")):
+                    return line.split(":", 1)[1].strip()
+    except OSError:
+        pass
+    return platform.machine()
+
+
+def lib_path() -> str:
+    """Where the library built from this source on this host lives."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as fp:
+        h.update(fp.read())
+    h.update(" ".join(_CXXFLAGS).encode())
+    h.update(_host_cpu_flags().encode())
+    return os.path.join(_DIR, f"librs_native-{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> str:
     # Compile to a pid-suffixed temp and atomically rename so concurrent
     # processes never dlopen a half-written .so.
-    tmp = f"{_SO}.{os.getpid()}.tmp"
-    cmd = [
-        "g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-        "-o", tmp, _SRC, "-lpthread",
-    ]
+    tmp = f"{so}.{os.getpid()}.tmp"
+    cmd = ["g++", *_CXXFLAGS, "-o", tmp, _SRC, "-lpthread"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
-        os.replace(tmp, _SO)
+        os.replace(tmp, so)
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
-    return _SO
+    return so
 
 
 def get_lib() -> ctypes.CDLL:
@@ -73,9 +97,10 @@ def get_lib() -> ctypes.CDLL:
         if _build_failed:
             raise NativeUnavailable("native build failed earlier this session")
         try:
-            if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-                _build()
-            lib = ctypes.CDLL(_SO)
+            so = lib_path()
+            if not os.path.exists(so):
+                _build(so)
+            lib = ctypes.CDLL(so)
         except (OSError, subprocess.CalledProcessError) as e:
             _build_failed = True
             raise NativeUnavailable(f"cannot build/load rs_native: {e}") from e

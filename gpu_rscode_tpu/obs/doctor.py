@@ -84,7 +84,7 @@ def _native_section() -> dict:
     try:
         from .. import native
 
-        out["lib_path"] = getattr(native, "_SO", None)
+        out["lib_path"] = native.lib_path()
         src = getattr(native, "_SRC", None)
         if src and os.path.exists(src):
             with open(src, "rb") as fp:
@@ -115,16 +115,7 @@ def _mesh_section(jax_info: dict) -> dict:
             out["distributed_env"][var] = os.environ[var]
     jax = sys.modules.get("jax")
     if jax is not None:
-        # Resolved through the compat shim (parallel/_compat.py): old
-        # jax pins serve jax.experimental.shard_map — the lookup that
-        # carried the 14-test mesh failure set until it was shimmed
-        # (docs/STATUS.md, ROADMAP item 4).
-        try:
-            from ..parallel._compat import shard_map_available
-
-            out["shard_map_available"] = shard_map_available()
-        except Exception:
-            out["shard_map_available"] = hasattr(jax, "shard_map")
+        out["shard_map_available"] = hasattr(jax, "shard_map")
     return out
 
 

@@ -17,6 +17,22 @@ from __future__ import annotations
 import os
 
 
+def _enable_cpu_collectives() -> None:
+    """Select the gloo CPU collectives implementation when the option is
+    still at its 'none' default.
+
+    Multi-process jobs on the CPU backend (the 2-process integration
+    tests, CPU-only fleet tooling) need a cross-process collectives
+    layer or XLA refuses with "Multiprocess computations aren't
+    implemented on the CPU backend".  Must run before the CPU client
+    initialises; harmless on TPU (the knob only steers CPU client
+    construction)."""
+    import jax
+
+    if jax.config._read("jax_cpu_collectives_implementation") == "none":
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
+
+
 def initialize(
     coordinator_address: str | None = None,
     num_processes: int | None = None,
@@ -35,9 +51,7 @@ def initialize(
     """
     import jax
 
-    from . import _compat
-
-    if _compat.distributed_is_initialized():
+    if jax.distributed.is_initialized():
         _mark_telemetry_epoch(jax)
         return  # idempotent: callers (library AND cli) may both invoke this
 
@@ -49,15 +63,15 @@ def initialize(
     if process_id is None and os.environ.get("JAX_PROCESS_ID"):
         process_id = int(os.environ["JAX_PROCESS_ID"])
     if auto or os.environ.get("RS_DISTRIBUTED") == "auto":
-        _compat.enable_cpu_collectives()
+        _enable_cpu_collectives()
         jax.distributed.initialize()
         _mark_telemetry_epoch(jax)
         return
     if coordinator_address is None and num_processes is None and process_id is None:
         return  # single process, nothing configured
     # CPU-backend multi-process jobs need a collectives layer (gloo)
-    # selected before the client initialises; see parallel/_compat.py.
-    _compat.enable_cpu_collectives()
+    # selected before the client initialises.
+    _enable_cpu_collectives()
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

@@ -158,43 +158,33 @@ class StagedSegment:
     Marks ownership transfer: the wrapped array was created by
     :func:`stage_segment` for exactly one GEMM dispatch, so the plan layer
     may DONATE its device buffer.  ``cols`` is the true (pre-pad) column
-    count; ``cap`` the plan cap it was bucketed under.  ``host`` keeps the
-    (padded) host copy alive until the dispatch succeeds: if a donating
-    dispatch fails after invalidating the device buffer (pallas demote
-    path), the codec re-stages from it instead of reading a deleted array.
+    count; ``cap`` the plan cap it was bucketed under.
     """
 
-    __slots__ = ("array", "cols", "cap", "host")
+    __slots__ = ("array", "cols", "cap")
 
-    def __init__(self, array, cols: int, cap: int | None, host=None):
+    def __init__(self, array, cols: int, cap: int | None):
         self.array = array
         self.cols = cols
         self.cap = cap
-        self.host = host
 
     @property
     def shape(self):
         return self.array.shape
 
 
-def stage_segment(B, cap: int | None, retain_host: bool = True):
+def stage_segment(B, cap: int | None):
     """Pad a host segment to its bucket and issue its (async) H2D transfer.
 
     This is the H2D stage of the 3-stage pipeline (see
     ``parallel.pipeline.DeviceStagingRing``): ``jax.device_put`` returns
     immediately, so the transfer of segment i+1 overlaps segment i's
     compute.  The zero pad is written host-side (one bounded memcpy) so the
-    staged buffer is exactly the plan's compiled shape.  The host copy is
-    retained only where a dispatch could DONATE the device buffer (and so
-    might need to re-stage after a donating failure): donation enabled AND
-    the caller says the coming dispatch is aliasable (``retain_host`` —
-    encode's p < k output can never alias, so its ring holds no extra host
-    memory beyond the prefetcher's own window).
+    staged buffer is exactly the plan's compiled shape.
     """
     import jax
 
     padded = _pad_to(B, bucket_cols(B.shape[1], cap))
-    host = padded if retain_host and _donation_allowed() else None
     prof_on = _prof.enabled()
     t0 = time.monotonic() if prof_on else 0.0
     with _tracing.span("h2d_stage", lane="stage", cols=int(B.shape[1]),
@@ -210,14 +200,7 @@ def stage_segment(B, cap: int | None, retain_host: bool = True):
         "rs_segments_staged_total",
         "segments bucket-padded and staged onto the device (H2D issued)",
     ).inc()
-    if host is not None:
-        # Donation watermark (obs/attrib.py): the extra host memory the
-        # donation-recovery copies pin while their segment is in flight.
-        _metrics.counter(
-            "rs_donation_host_copy_bytes_total",
-            "bytes of retained host copies backing donatable segments",
-        ).inc(int(host.nbytes))
-    return StagedSegment(staged, B.shape[1], cap, host=host)
+    return StagedSegment(staged, B.shape[1], cap)
 
 
 class ExecutionPlan:
@@ -279,8 +262,7 @@ class ExecutionPlan:
     def _build(self, A, B, donate: bool):
         """Lower + compile this plan's executable for concrete operands.
         Runs under the plan's own lock (see :meth:`run`); compile errors
-        propagate to the dispatch site, where the codec's pallas guard can
-        demote exactly like an eager failure."""
+        propagate to the dispatch site."""
         w, strategy = self.w, self.strategy
         if strategy == "ring":
             # Same contract as the xor branch below, with the ring

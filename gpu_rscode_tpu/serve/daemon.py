@@ -1629,6 +1629,10 @@ def main(argv=None) -> int:
     """The ``rs serve`` subcommand."""
     import argparse
 
+    from ..utils.backend import configure_compile_cache
+
+    configure_compile_cache()
+
     ap = argparse.ArgumentParser(
         prog="rs serve",
         description="Resident multi-tenant encode/decode daemon: "

@@ -42,7 +42,7 @@ def main() -> int:
         invert_matrix_jax_batch,
         mds_nopivot_order,
     )
-    from ..utils.backend import backend_label
+    from ..utils.backend import device_platform
 
     import jax
 
@@ -50,7 +50,7 @@ def main() -> int:
 
     print(json.dumps(capture_header("inverse_bench")), flush=True)
 
-    label = backend_label()
+    label = device_platform()
     print(f"# backend={label}", file=sys.stderr, flush=True)
     gf = get_field(8)
     rng = np.random.default_rng(0)

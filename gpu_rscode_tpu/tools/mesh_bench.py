@@ -4,11 +4,10 @@ VERDICT r4 gap 2: ``strategy="auto"`` on a mesh resolves to the fused
 kernel (codec.py), but every mesh test runs interpret-mode on the virtual
 CPU mesh and every real-TPU capture is single-device *unsharded*.  If
 Mosaic refused the kernel inside ``shard_map`` on hardware, production
-multi-chip would silently demote to the ~13 GB/s bitplane path.  This tool
-closes that gap on a real chip: it builds a 1-device ``(stripe, cols)``
-mesh over the TPU and dispatches the PRODUCTION sharded paths directly
-(``parallel.sharded.sharded_gf_matmul`` — no demotion guard, so a Mosaic
-refusal propagates and the committed log IS the deliverable):
+multi-chip would fail.  This tool closes that gap on a real chip: it
+builds a 1-device ``(stripe, cols)`` mesh over the TPU and dispatches the
+PRODUCTION sharded paths directly (``parallel.sharded.sharded_gf_matmul``
+— a Mosaic refusal propagates and the committed log IS the deliverable):
 
 * ``cols_pallas``   — cols-sharded fused kernel (the zero-comm production
   mesh path; reference analog: its multi-GPU mode provably runs the same
@@ -17,7 +16,7 @@ refusal propagates and the committed log IS the deliverable):
   (``fold_parity=False``) + integer ``psum`` + fold: exercises BOTH the
   kernel's pre-parity emission and an XLA collective around it on
   hardware.
-* ``cols_bitplane`` — the demotion target, for the same-shape comparison.
+* ``cols_bitplane`` — the XLA bitplane path, for the same-shape comparison.
 
 Each mode is bit-verified against the native CPU oracle on a slab before
 timing.  Prints one commented-jsonl verdict per mode.
@@ -46,7 +45,7 @@ def main() -> int:
     from ..models.vandermonde import vandermonde_matrix
     from ..parallel.mesh import make_mesh
     from ..parallel.sharded import put_sharded, sharded_gf_matmul
-    from ..utils.backend import backend_label
+    from ..utils.backend import device_platform
     from ._bench_timing import time_device_fn
 
     import jax
@@ -55,7 +54,7 @@ def main() -> int:
 
     print(json.dumps(capture_header("mesh_bench")), flush=True)
 
-    label = backend_label()
+    label = device_platform()
     k, p = args.k, args.p
     m = (args.mb * 1024 * 1024) // k
     m = (m // 1024) * 1024  # lane-align so every mode shares one shape

@@ -53,7 +53,7 @@ def main() -> int:
     from ..ops.gemm import gf_matmul_jit
     from ..parallel.mesh import make_mesh
     from ..parallel.sharded import put_sharded, sharded_gf_matmul
-    from ..utils.backend import backend_label
+    from ..utils.backend import device_platform
 
     import jax
 
@@ -61,7 +61,7 @@ def main() -> int:
 
     print(json.dumps(capture_header("mesh_overhead")), flush=True)
 
-    label = backend_label()
+    label = device_platform()
     k, p = args.k, args.p
     n_dev = len(jax.devices())
     mesh = make_mesh(n_dev, stripe=1)

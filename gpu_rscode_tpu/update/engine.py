@@ -453,7 +453,7 @@ def _stage_block(
         "dispatch", lane="dispatch", op=op, off=int(b0), cols=int(b1 - b0)
     ):
         staged = codec.stage_segment(
-            delta, cap=cap_bytes // sym, sym=sym, out_rows=p
+            delta, cap=cap_bytes // sym, sym=sym
         )
         pd = codec.update(E, staged)  # async E·Δ through the plan cache
     return _collect_block(

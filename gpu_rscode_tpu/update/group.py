@@ -399,7 +399,7 @@ def _apply_group(file_name, edits, *, base, strategy, segment_bytes,
                         "dispatch", lane="dispatch", **span_args,
                     ):
                         staged = codec.stage_segment(
-                            stacked, cap=step // sym, sym=sym, out_rows=p
+                            stacked, cap=step // sym, sym=sym
                         )
                         pd = codec.update(E, staged)
                     with timer.phase("update compute"):

@@ -281,9 +281,9 @@ def test_pack2_expand_survives_plan_aot_rebuild(monkeypatch):
     assert c.strategy == "pallas"  # never demoted
 
 
-def test_pallas_failure_still_demotes_under_plan(monkeypatch):
-    """A Mosaic-class failure on the first (eager) dispatch demotes to
-    bitplane exactly as before the plan layer existed."""
+def test_pallas_failure_raises_under_plan(monkeypatch):
+    """A fused-kernel failure on the first (eager) dispatch of a staged
+    segment raises, naming the shapes; nothing runs in its place."""
     import jax
 
     from gpu_rscode_tpu import codec as codec_mod
@@ -297,12 +297,9 @@ def test_pallas_failure_still_demotes_under_plan(monkeypatch):
     c = RSCodec(4, 2, strategy="pallas")
     rng = np.random.default_rng(23)
     B = rng.integers(0, 256, size=(4, 512), dtype=np.uint8)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        out = np.asarray(c.encode(c.stage_segment(B.copy(), cap=512)))
-    assert c.strategy == "bitplane"
-    assert any("falling back" in str(w.message) for w in caught)
-    np.testing.assert_array_equal(out, c.gf.matmul(c.parity_block, B))
+    with pytest.raises(codec_mod.FusedKernelError, match=r"B\(4, 512\)"):
+        c.encode(c.stage_segment(B.copy(), cap=512))
+    assert c.strategy == "pallas"
 
 
 # ----- compile-count guard (tier-1, ISSUE acceptance) -----------------------

@@ -1,6 +1,8 @@
 """Sharded GEMM on the 8-device virtual CPU mesh: results must be identical
 to the single-device oracle for every mesh shape and sharding mode."""
 
+import os
+
 import jax
 import numpy as np
 import pytest
@@ -164,51 +166,42 @@ def test_auto_on_mesh_resolves_to_pallas_on_tpu(monkeypatch):
     assert c2.strategy == "bitplane"
 
 
-def test_mesh_pallas_validate_once_demotes_at_startup(monkeypatch):
-    """The sharded validate-once gate: a Mosaic-class failure on the FIRST
-    dispatch demotes to bitplane with a warning and still returns correct
-    output; the demotion is sticky (no per-segment retries)."""
-    from gpu_rscode_tpu.codec import RSCodec
+def test_mesh_pallas_failure_raises(monkeypatch):
+    """A fused-kernel failure on the mesh raises with the shape and the
+    mesh in its message, once, with no retry on another strategy."""
+    from gpu_rscode_tpu.codec import FusedKernelError, RSCodec
     from gpu_rscode_tpu.parallel import sharded as sharded_mod
 
-    real = sharded_mod.sharded_gf_matmul
     calls = []
 
     def fake(A, B, *, mesh, w=8, strategy="bitplane", stripe_sharded=False):
         calls.append(strategy)
-        if strategy == "pallas":
-            raise NotImplementedError("synthetic Mosaic lowering failure")
-        return real(
-            A, B, mesh=mesh, w=w, strategy=strategy,
-            stripe_sharded=stripe_sharded,
-        )
+        raise NotImplementedError("synthetic Mosaic lowering failure")
 
     monkeypatch.setattr(sharded_mod, "sharded_gf_matmul", fake)
     mesh = make_mesh(8)
-    A, B, want = _case(4, 10, 8 * 256, seed=7)
+    A, B, _ = _case(4, 10, 8 * 256, seed=7)
     c = RSCodec(10, 4, strategy="pallas", mesh=mesh)
-    with pytest.warns(UserWarning, match="demoting to the XLA bitplane"):
-        got = np.asarray(c._matmul(A, B))
-    np.testing.assert_array_equal(got, want)
-    assert c.strategy == "bitplane"
-    # Second segment: no pallas retry, straight to the demoted strategy.
-    got2 = np.asarray(c._matmul(A, B))
-    np.testing.assert_array_equal(got2, want)
-    assert calls == ["pallas", "bitplane", "bitplane"]
+    with pytest.raises(FusedKernelError, match=r"A\(4, 10\).*mesh") as e:
+        c._matmul(A, B)
+    assert isinstance(e.value.__cause__, NotImplementedError)
+    assert c.strategy == "pallas" and calls == ["pallas"]
 
 
-def test_mesh_pallas_non_mosaic_failure_propagates(monkeypatch):
-    """Only known backend/Mosaic failure types demote — a programming error
-    (TypeError) must propagate, not silently fall back."""
-    from gpu_rscode_tpu.codec import RSCodec
+def test_mesh_pallas_failure_leaves_no_archive(monkeypatch, tmp_path):
+    """encode_file on a mesh whose fused kernel fails raises and leaves
+    no chunk, metadata or .rs_tmp file behind."""
+    from gpu_rscode_tpu import api
+    from gpu_rscode_tpu.codec import FusedKernelError
     from gpu_rscode_tpu.parallel import sharded as sharded_mod
 
     def boom(A, B, **kw):
         raise TypeError("shape bug")
 
     monkeypatch.setattr(sharded_mod, "sharded_gf_matmul", boom)
-    mesh = make_mesh(8)
-    A, B, _ = _case(4, 10, 8 * 256, seed=8)
-    c = RSCodec(10, 4, strategy="pallas", mesh=mesh)
-    with pytest.raises(TypeError, match="shape bug"):
-        c._matmul(A, B)
+    path = tmp_path / "f.bin"
+    path.write_bytes(np.random.default_rng(8).bytes(50_000))
+    with pytest.raises(FusedKernelError, match="shape bug"):
+        api.encode_file(str(path), 10, 4, strategy="pallas",
+                        mesh=make_mesh(8))
+    assert os.listdir(tmp_path) == ["f.bin"]
