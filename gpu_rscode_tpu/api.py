@@ -134,8 +134,14 @@ def _profile_session():
     try:
         import jax
 
-        with jax.profiler.trace(profile_dir):
-            yield
+        # The program's spans go into the capture too (rs.<name>), on
+        # the profiler's clock beside the device's ops.
+        was = _obs_tracing.profiler_sink(True)
+        try:
+            with jax.profiler.trace(profile_dir):
+                yield
+        finally:
+            _obs_tracing.profiler_sink(was)
     finally:
         with _PROFILE_LOCK:
             _PROFILE_ACTIVE = False
@@ -566,18 +572,20 @@ def _write_native_chunks(
             lo, hi = i * chunk, min((i + 1) * chunk, total_size)
             crc = 0
             with open(tmps[chunk_file_name(file_name, i)], "wb") as fp:
-                for s in range(lo, hi, copy_step):
-                    buf = src[s : min(s + copy_step, hi)].tobytes()
+
+                def put(buf: bytes) -> None:
+                    nonlocal crc
                     fp.write(buf)
                     if crcs is not None:
-                        crc = crc32_of(buf, crc)
+                        with _obs_tracing.span("crc", lane="crc"):
+                            crc = crc32_of(buf, crc)
+
+                for s in range(lo, hi, copy_step):
+                    put(src[s : min(s + copy_step, hi)].tobytes())
                 pad = chunk - max(0, hi - lo)
                 zeros = b"\x00" * min(pad, copy_step)
                 for s in range(0, pad, copy_step):
-                    buf = zeros[: min(copy_step, pad - s)]
-                    fp.write(buf)
-                    if crcs is not None:
-                        crc = crc32_of(buf, crc)
+                    put(zeros[: min(copy_step, pad - s)])
             if crcs is not None:
                 crcs[i] = crc
 
@@ -795,13 +803,13 @@ def _drain_parity(entry, parity_files, timer, crcs=None, k=0) -> None:
     # lands: the writer lane may retry this whole drain on a transient
     # write error (docs/RESILIENCE.md), and a half-committed accumulator
     # would silently corrupt the checksum lines.
-    new_crcs = (
-        {
-            k + j: crc32_of(parity_np[j], crcs.get(k + j, 0))
-            for j in range(parity_np.shape[0])
-        }
-        if crcs is not None else None
-    )
+    new_crcs = None
+    if crcs is not None:
+        with _obs_tracing.span("crc", lane="crc"):
+            new_crcs = {
+                k + j: crc32_of(parity_np[j], crcs.get(k + j, 0))
+                for j in range(parity_np.shape[0])
+            }
     with timer.phase("write parity (io)"):
         native.scatter_write(parity_files, parity_np, off)
     if new_crcs is not None:
@@ -2104,8 +2112,10 @@ def _scan_chunks(in_file: str, segment_bytes: int) -> _ChunkScan:
     and its per-chunk verdicts (``rs_scrub_archives_scanned_total`` /
     ``rs_scrub_chunks_total{state}``) and records one span on the
     ``scrub`` lane, so fleet-wide health sweeps (scan_file, repair_fleet,
-    auto-decode discovery) all feed the same series.
+    auto-decode discovery) all feed the same series, and the seconds of
+    every completed scan go to ``rs_scrub_scan_seconds_total``.
     """
+    t0 = time.perf_counter()
     with _obs_tracing.span("scan_chunks", lane="scrub", file=in_file):
         meta_path = metadata_file_name(in_file)
         meta = read_archive_meta(meta_path)
@@ -2180,6 +2190,10 @@ def _scan_chunks(in_file: str, segment_bytes: int) -> _ChunkScan:
             "scan", in_file, states=damage_states, k=k, p=p, w=w,
             generation=meta.generation,
         )
+        _obs_metrics.counter(
+            "rs_scrub_scan_seconds_total",
+            "wall seconds of completed archive health scans",
+        ).inc(time.perf_counter() - t0)
         return _ChunkScan(
             in_file, total_size, p, k, total_mat, w, crcs, chunk, healthy,
             bad, layout=meta.layout, generation=meta.generation,
@@ -3127,11 +3141,11 @@ def _repair_streamed(
             reb = np.ascontiguousarray(reb).view(np.uint8)
         # CRC advance committed only after the write lands — the writer
         # lane may retry this whole drain (see _drain_parity).
-        delta = (
-            {t: crc32_of(reb[j], new_crcs.get(t, 0))
-             for j, t in enumerate(targets)}
-            if scan.crcs else None
-        )
+        delta = None
+        if scan.crcs:
+            with _obs_tracing.span("crc", lane="crc"):
+                delta = {t: crc32_of(reb[j], new_crcs.get(t, 0))
+                         for j, t in enumerate(targets)}
         with timer.phase("write chunks (io)"):
             native.scatter_write([out_fps[t] for t in targets], reb, off)
         if delta is not None:

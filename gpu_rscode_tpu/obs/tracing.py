@@ -15,18 +15,26 @@ finished span intact.  Lanes map to ``tid`` with ``thread_name`` metadata
 events; counter tracks (``ph="C"``, e.g. staging-ring occupancy) render as
 Perfetto counter lanes.
 
-Off by default: with no active session, :func:`span` returns a shared
+Spans have two sinks, each of which may be on alone or with the other:
+the session's JSON file above, and the **profiler sink**
+(:func:`profiler_sink`), under which every span also opens a
+``jax.profiler.TraceAnnotation`` named ``rs.<name>`` — on the profiler's
+clock, in the same ``.xplane.pb`` as the device's ops, so a device idle
+gap can be named by what the host threads were doing in it.  The
+annotation carries no args (no formatting on the hot path, stable names).
+``RS_PROFILE`` captures (``api._profile_session``) turn it on.
+
+Off by default: with neither sink on, :func:`span` returns a shared
 ``nullcontext`` and :func:`instant`/:func:`counter` return immediately —
 the disabled path is one module-global read (same tier-1 overhead guard as
-the metrics registry; see docs/OBSERVABILITY.md for the interaction with
-``profile_dir``/``jax.profiler``, which remains the deep-profiling tool).
+the metrics registry; see docs/OBSERVABILITY.md).
 
-Import cost: stdlib only (no jax, no numpy).
+Import cost: stdlib only (no jax, no numpy); jax is imported when the
+profiler sink is turned on.
 """
 
 from __future__ import annotations
 
-import functools
 import json
 import os
 import socket
@@ -230,21 +238,68 @@ class Tracer:
 # -- module-level session ----------------------------------------------------
 
 _ACTIVE: Tracer | None = None
+# jax.profiler.TraceAnnotation while the profiler sink is on, else None.
+_ANNOTATION = None
+# True while either sink is on: the one global span()'s off path reads.
+_ON = False
 _SESSION_LOCK = threading.Lock()
+
+# Prefix of every span's name in the profiler's trace.
+PROFILER_PREFIX = "rs."
+
+
+def _refresh() -> None:
+    """Recompute ``_ON``; call with ``_SESSION_LOCK`` held after a sink
+    changes."""
+    global _ON
+    _ON = _ACTIVE is not None or _ANNOTATION is not None
 
 
 def active() -> Tracer | None:
-    """The session tracer, or None when tracing is off."""
+    """The session tracer, or None when no JSON session is active."""
     return _ACTIVE
 
 
+def enabled() -> bool:
+    """True while either sink (JSON session or profiler) records spans."""
+    return _ON
+
+
+def profiler_sink(on: bool) -> bool:
+    """Turn the profiler sink on or off for the whole process; returns
+    whether it was on, so a caller can restore it.
+
+    While on, every :func:`span` also opens
+    ``jax.profiler.TraceAnnotation("rs." + name)``, which a running
+    ``jax.profiler`` capture records on the thread that opened it."""
+    global _ANNOTATION
+    ann = None
+    if on:
+        from jax.profiler import TraceAnnotation as ann
+    with _SESSION_LOCK:
+        was = _ANNOTATION is not None
+        _ANNOTATION = ann
+        _refresh()
+    return was
+
+
+@contextmanager
+def _both(outer, inner):
+    with outer, inner:
+        yield
+
+
 def span(name: str, lane: str = "host", **args):
-    """Record a span on the active session; no-op context manager when
-    tracing is off (the hot-path entry point — one global read)."""
-    t = _ACTIVE
-    if t is None:
+    """Record a span on every sink that is on; a shared no-op context
+    manager when none is (the hot-path entry point — one global read)."""
+    if not _ON:
         return _NULL_CM
-    return t.span(name, lane, **args)
+    t, ann = _ACTIVE, _ANNOTATION
+    if ann is None:
+        return t.span(name, lane, **args) if t is not None else _NULL_CM
+    if t is None:
+        return ann(PROFILER_PREFIX + name)
+    return _both(ann(PROFILER_PREFIX + name), t.span(name, lane, **args))
 
 
 def instant(name: str, lane: str = "host", **args) -> None:
@@ -268,25 +323,6 @@ def complete(name: str, lane: str, t0_mono: float, t1_mono: float,
         t.complete(name, lane, t0_mono, t1_mono, **args)
 
 
-def traced(name: str | None = None, lane: str = "host"):
-    """Decorator form of :func:`span` (zero overhead when tracing is off)."""
-
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            t = _ACTIVE
-            if t is None:
-                return fn(*a, **kw)
-            with t.span(label, lane):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco
-
-
 @contextmanager
 def session(path: str | None = None):
     """Activate tracing for a region and export on exit.
@@ -307,6 +343,7 @@ def session(path: str | None = None):
     with _SESSION_LOCK:
         if path and _ACTIVE is None:
             owner = _ACTIVE = Tracer(path)
+            _refresh()
         elif path and _ACTIVE is not None and path != _ACTIVE.path:
             import warnings
 
@@ -322,6 +359,7 @@ def session(path: str | None = None):
         if owner is not None:
             with _SESSION_LOCK:
                 _ACTIVE = None
+                _refresh()
             try:
                 owner.export()
             except (OSError, TypeError, ValueError) as e:

@@ -417,6 +417,7 @@ def _pallas_matmul_pack2(A, B, w, tile, interpret):
         ],
         out_specs=pl.BlockSpec((p, tile2), lambda i: (0, i)),
         interpret=interpret,
+        name="gf_matmul_pack2",
     )(a_op, B16)
     out = jax.lax.bitcast_convert_type(out16, jnp.uint8).reshape(p, 2 * m2)
     return out[:, :m] if pad else out
@@ -489,6 +490,8 @@ def _pallas_matmul(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((out_rows, tile), lambda i: (0, i)),
         interpret=interpret,
+        # A stable kernel name for the profiler's device ops.
+        name="gf_matmul",
     )(*operands)
 
 

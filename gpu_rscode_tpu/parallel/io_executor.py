@@ -39,7 +39,9 @@ Knobs: ``RS_IO_WRITERS`` (writer threads; 0 = synchronous drain; default
 2 x writers), ``RS_IO_READERS`` (fallback read pool; default
 min(4, cores)).  Observability (docs/OBSERVABILITY.md): the
 ``rs_io_*`` counters/gauges and per-lane ``write_drain`` spans recorded
-here make the overlap visible in Perfetto.
+here make the overlap visible in Perfetto; a ``submit`` that blocks on a
+full queue records a ``wait_writer`` span and
+``rs_pipeline_wait_seconds_total{wait="writer"}``.
 
 Import cost: stdlib only (no jax, no numpy) — same contract as ``obs``.
 """
@@ -54,6 +56,7 @@ from collections.abc import Callable
 
 from ..obs import metrics as _metrics, tracing as _tracing
 from ..resilience import faults as _faults, retry as _retry
+from .pipeline import blocked
 
 
 def writer_count(default: int = 1) -> int:
@@ -229,7 +232,11 @@ class DrainExecutor:
                 "submit() (worker threads not started)"
             )
         self._check_error()
-        self._q.put((fn, nbytes))
+        try:
+            self._q.put_nowait((fn, nbytes))
+        except queue.Full:
+            with blocked("writer"):
+                self._q.put((fn, nbytes))
         self._report_depth()
 
     def submit_pwrite(self, fileno: int, data: bytes, offset: int) -> None:

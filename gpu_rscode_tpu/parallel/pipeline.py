@@ -16,7 +16,9 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from collections.abc import Callable, Iterable
+from contextlib import contextmanager
 from typing import Any, Generic, TypeVar
 
 from ..obs import metrics as _metrics, tracing as _tracing
@@ -27,6 +29,27 @@ T = TypeVar("T")
 # DeviceStagingRing._report_occupancy).  Held in a list so tests can
 # reset it without rebinding the module attribute they imported.
 _RING_PEAK = [0]
+
+
+@contextmanager
+def blocked(wait: str):
+    """One blocking wait of a pipeline thread: a ``wait_<wait>`` span on
+    the waiting thread and its seconds in
+    ``rs_pipeline_wait_seconds_total{wait}``.  Callers enter it only after
+    a non-blocking try failed, so a wait that does not block costs
+    nothing."""
+    t0 = time.perf_counter()
+    try:
+        with _tracing.span(
+            "wait_" + wait, lane="wait:" + threading.current_thread().name
+        ):
+            yield
+    finally:
+        _metrics.counter(
+            "rs_pipeline_wait_seconds_total",
+            "seconds a pipeline thread blocked: on a full writer queue "
+            "(writer) or an empty segment queue (segment)",
+        ).labels(wait=wait).inc(time.perf_counter() - t0)
 
 
 class AsyncWindow(Generic[T]):
@@ -270,7 +293,11 @@ class SegmentPrefetcher:
                 "SegmentPrefetcher must be used as a context manager "
                 "(worker thread not started; iterate inside 'with')"
             )
-        tag, item = self._q.get()
+        try:
+            tag, item = self._q.get_nowait()
+        except queue.Empty:
+            with blocked("segment"):
+                tag, item = self._q.get()
         if tag is self._STOP:
             self._stop.set()  # idempotent; lets join() return fast
             if item is not None:

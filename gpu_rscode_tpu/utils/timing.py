@@ -10,10 +10,9 @@ computation/communication split so numbers are comparable.
 Phases integrate with the unified observability layer (``..obs``): when a
 tracing session is active (``RS_TRACE``), every timed phase also lands as
 a span on the ``phase`` lane of the exported Perfetto trace — the timer
-stays the human-readable report, the trace the per-event timeline.
-
-For deep profiling use ``jax.profiler.trace`` via the ``profile_dir``
-option on the file APIs (the TPU-native answer to nvprof/ptxas stats).
+stays the human-readable report, the trace the per-event timeline.  Under
+the profiler sink (``RS_PROFILE``) each phase is an ``rs.<phase>`` span
+of the ``jax.profiler`` capture, beside the device's ops.
 """
 
 from __future__ import annotations
@@ -69,9 +68,10 @@ class PhaseTimer:
     def phase(self, name: str):
         if not self.enabled:
             # A disabled timer never accumulates, but an active RS_TRACE
-            # session still gets the phase span — the file APIs default to
-            # a disabled timer, and the trace must not go blind there.
-            if _tracing.active() is None:
+            # session or profiler sink still gets the phase span — the
+            # file APIs default to a disabled timer, and the trace must
+            # not go blind there.
+            if not _tracing.enabled():
                 yield
                 return
             with _tracing.span(

@@ -1,0 +1,9 @@
+"""Share (%) of the encode cell's traced window in which the dispatching
+thread was blocked waiting for the next staged segment (the pread
+gather on the prefetch thread)."""
+
+from rsbench.program_counters import wait_share
+
+
+def read(run):
+    return wait_share(run, "segment")

@@ -304,7 +304,12 @@ def test_disabled_attribution_path_registers_nothing(tmp_path,
 
 def test_profile_env_wraps_file_op(tmp_path, monkeypatch):
     """RS_PROFILE=<dir> captures a jax.profiler trace around a library
-    call — no CLI involved (the lifted satellite surface)."""
+    call — no CLI involved (the lifted satellite surface) — and the
+    capture carries the program's spans, the sink off again after."""
+    from gpu_rscode_tpu.obs import tracing
+
+    from test_obs import xplane_spans
+
     prof = tmp_path / "prof"
     monkeypatch.setenv("RS_PROFILE", str(prof))
     path = _mkfile(tmp_path, 40_000)
@@ -312,6 +317,10 @@ def test_profile_env_wraps_file_op(tmp_path, monkeypatch):
     assert prof.exists() and any(prof.rglob("*")), (
         "RS_PROFILE set but no jax.profiler capture landed"
     )
+    names = {n for n, _ in xplane_spans(prof)}
+    assert {"rs.encode", "rs.dispatch", "rs.write_drain",
+            "rs.encode compute"} <= names
+    assert not tracing.enabled()
 
 
 def test_profile_override_cleared_by_cli(tmp_path):

@@ -8,7 +8,11 @@ instrumentation in the hot loop), and the ``rs stats`` / ``--metrics-json``
 round-trip whose snapshot matches the run it instrumented.
 """
 
+import glob
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -18,7 +22,10 @@ import pytest
 from gpu_rscode_tpu import api, plan
 from gpu_rscode_tpu.obs import metrics, tracing
 from gpu_rscode_tpu.tools.make_conf import make_conf
+from gpu_rscode_tpu.utils.fileformat import chunk_file_name
 from gpu_rscode_tpu.utils.timing import PhaseTimer
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
@@ -194,17 +201,106 @@ def test_trace_export_survives_numpy_span_args(tmp_path):
     assert not (tmp_path / "np.json.rs_tmp").exists()
 
 
-def test_traced_decorator(tmp_path):
-    @tracing.traced("decorated", lane="fn")
-    def f(x):
-        return x + 1
+# ----- the profiler sink ----------------------------------------------------
 
-    assert f(1) == 2  # no session: plain call
-    out = str(tmp_path / "d.json")
-    with tracing.session(out):
-        assert f(2) == 3
-    trace = json.load(open(out))
-    assert any(e["name"] == "decorated" for e in trace["traceEvents"])
+
+def xplane_spans(log_dir) -> list[tuple[str, float]]:
+    """(name, seconds) of every program span (``rs.``) in the one
+    jax.profiler capture under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    [path] = glob.glob(os.path.join(
+        str(log_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    return [(e.name, e.duration_ns / 1e9)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith(tracing.PROFILER_PREFIX)]
+
+
+@pytest.fixture
+def profiler_sink():
+    was = tracing.profiler_sink(True)
+    yield
+    tracing.profiler_sink(was)
+
+
+def test_span_off_path_builds_no_annotation(monkeypatch):
+    """Both sinks off: span() hands back the shared null context and never
+    constructs a TraceAnnotation, from the phase timer's gate either."""
+    import jax.profiler
+
+    def boom(*a, **kw):
+        raise AssertionError("a TraceAnnotation was built with tracing off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    assert not tracing.enabled() and tracing.active() is None
+    assert tracing.span("dispatch", lane="dispatch", off=1) is tracing._NULL_CM
+    with PhaseTimer(enabled=False).phase("encode compute"):
+        pass
+
+
+def test_tracing_imports_no_jax():
+    code = ("import sys, gpu_rscode_tpu.obs.tracing; "
+            "print('jax' in sys.modules)")
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, timeout=120,
+                         capture_output=True, text=True, check=True)
+    assert out.stdout.strip() == "False"
+
+
+def test_both_sinks_record_the_same_span(tmp_path, profiler_sink):
+    import jax
+
+    out = str(tmp_path / "t.json")
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        with tracing.session(out):
+            with tracing.span("outer", lane="work", step=1):
+                with PhaseTimer(enabled=False).phase("inner (io)"):
+                    time.sleep(0.002)
+    names = [n for n, _ in xplane_spans(tmp_path / "prof")]
+    assert names.count("rs.outer") == 1 and "rs.inner (io)" in names
+    evs = json.load(open(out))["traceEvents"]
+    assert {"outer", "inner (io)"} <= {e["name"] for e in evs}
+    tracing.profiler_sink(False)
+    assert not tracing.enabled()
+
+
+def test_file_path_spans_reach_the_profiler(tmp_path, clean_registry,
+                                            monkeypatch, profiler_sink):
+    """A small checksummed encode and a repair under jax.profiler leave
+    the file path's spans in the capture, each once per unit of work, and
+    a one-deep writer queue behind slow writes makes the dispatch thread
+    wait: its wait spans and rs_pipeline_wait_seconds_total agree."""
+    import jax
+
+    monkeypatch.setenv("RS_METRICS", "1")
+    monkeypatch.setenv("RS_IO_WRITE_DEPTH", "1")
+    monkeypatch.setenv("RS_FAULTS", "write:delay@ms=20")
+    monkeypatch.delenv("RS_TRACE", raising=False)
+    k, chunk = 4, 3000  # 1024-column segments: 3 per chunk
+    path = _mkfile(tmp_path, k * chunk)
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        api.encode_file(path, k, 2, segment_bytes=4096, checksums=True)
+        os.remove(chunk_file_name(path, 1))
+        assert api.repair_file(path, segment_bytes=4096) == [1]
+    spans = xplane_spans(tmp_path / "prof")
+    names = [n for n, _ in spans]
+    for once in ("rs.encode", "rs.repair", "rs.scan_chunks"):
+        assert names.count(once) == 1, once
+    assert names.count("rs.dispatch") == 6  # 3 segments, each op
+    # k native copies, 3 parity drains, 3 repair drains
+    assert names.count("rs.crc") == k + 3 + 3
+    assert names.count("rs.write_drain") >= k + 3 + 3
+    assert "rs.wait_writer" in names and "rs.write natives (io)" in names
+    waited = sum(d for n, d in spans if n.startswith("rs.wait_"))
+    snap = metrics.REGISTRY.snapshot()
+    counted = snap["rs_pipeline_wait_seconds_total"]["values"]
+    assert counted['{wait="writer"}'] >= 0.02
+    assert sum(counted.values()) == pytest.approx(waited, rel=0.05)
+    # The repair's scan, timed for operators as well as spanned.
+    scanned = sum(d for n, d in spans if n == "rs.scan_chunks")
+    assert snap["rs_scrub_scan_seconds_total"]["values"][""] == \
+        pytest.approx(scanned, rel=0.2, abs=0.002)
 
 
 # ----- wiring: a traced + metered encode ------------------------------------
