@@ -555,19 +555,14 @@ def _write_native_chunks(
     copy_step: int,
     crcs: dict[int, int] | None,
     timer: PhaseTimer,
-    executor=None,
 ) -> None:
     """Write the k native chunk temp files: straight copies of the k file
     ranges, tail zero-padded, in bounded slices (a 100 GB chunk never
-    materialises in RAM), with optional incremental CRC32.
+    materialises in RAM), with optional incremental CRC32.  The
+    multi-process lead's path; a single-process encode writes its natives
+    from the gathered segments instead (:func:`_drain_natives`)."""
 
-    With an ``executor`` (the encode's write-behind lane) each chunk copy
-    is queued as a drain task instead of running here: the dispatch thread
-    proceeds straight to parity streaming while the natives land on the
-    writer lane (tasks touch distinct files and distinct ``crcs`` keys, so
-    lane ordering is irrelevant; ``src`` is a read-only view)."""
-
-    def write_one(i: int) -> None:
+    for i in range(k):
         with timer.phase("write natives (io)"):
             lo, hi = i * chunk, min((i + 1) * chunk, total_size)
             crc = 0
@@ -588,12 +583,6 @@ def _write_native_chunks(
                     put(zeros[: min(copy_step, pad - s)])
             if crcs is not None:
                 crcs[i] = crc
-
-    for i in range(k):
-        if executor is not None:
-            executor.submit(lambda i=i: write_one(i), nbytes=chunk)
-        else:
-            write_one(i)
 
 
 @_observed_file_op("encode")
@@ -683,10 +672,6 @@ def encode_file(
     # to misread as a damaged archive (_ArchiveCommit).
     commit = _ArchiveCommit(file_name, k + p)
     written, tmps = commit.written, commit.tmps
-
-    # Native chunks: straight copies of the k file ranges, tail zero-padded.
-    # Copied in bounded slices so a 100 GB chunk never materialises in RAM.
-    copy_step = max(1, segment_bytes)
     crcs: dict[int, int] | None = {} if checksums else None
 
     def gather_segment(off: int, cols: int) -> np.ndarray:
@@ -707,13 +692,13 @@ def encode_file(
         with timer.phase("stage segment (io)"):
             return _retry.default_policy().call(attempt, op="encode_stage")
 
-    parity_files: list = []
+    files: list = []  # the k native then p parity temp files
 
     def finalize() -> None:
-        # The commit tail: runs only after every parity write landed — on
+        # The commit tail: runs only after every chunk write landed — on
         # the caller thread standalone, on the fleet's writer lane (behind
         # this file's drains) in batch mode.
-        for fp in parity_files:
+        for fp in files:
             fp.close()
         with timer.phase("write metadata (io)"):
             write_metadata(
@@ -721,10 +706,13 @@ def encode_file(
             )
             if crcs is not None:
                 append_checksums(commit.meta_tmp, crcs)
-        commit.promote()
+        # Renames over a previous archive's chunks can wait on the disk's
+        # writeback; the phase names that wait.
+        with timer.phase("promote chunks (io)"):
+            commit.promote()
 
     def cleanup() -> None:
-        for fp in parity_files:
+        for fp in files:
             if not fp.closed:
                 fp.close()
         commit.discard()
@@ -735,49 +723,53 @@ def encode_file(
     key = _fleet.register(cleanup) if _fleet is not None else None
     try:
         with _drain_ctx(_fleet) as dex:
-            # Native chunk copies ride the writer lane too: the dispatch
-            # thread proceeds straight to parity streaming while the k
-            # straight copies land write-behind (sync with RS_IO_WRITERS=0).
-            _write_native_chunks(
-                src, file_name, tmps, k, chunk, total_size, copy_step,
-                crcs, timer, executor=dex,
-            )
+            for name in written[:-1]:
+                files.append(open(tmps[name], "wb"))
+            natives, parities = files[:k], files[k:]
 
-            # Parity chunks: stream segments through the device, staging
-            # on a worker thread (SegmentPrefetcher) so read IO overlaps
-            # the drain's D2H + parity writes — the three-way overlap of
-            # the reference's stream loop (encode.cu:165-218).
-            for j in range(p):
-                parity_files.append(
-                    open(tmps[chunk_file_name(file_name, k + j)], "wb")
+            def drain(tag, payload) -> None:
+                # Two tasks on the ordered lane, natives first: the k
+                # native rows straight from the gathered host segment (so
+                # the source is read once), then the parity once its D2H
+                # lands, its wait hidden behind the native writes.
+                (off, cols), (host, parity) = tag, payload
+                dex.submit(
+                    lambda: _drain_natives(off, host, natives, timer, crcs),
+                    nbytes=host.nbytes,
                 )
+                dex.submit(
+                    lambda: _drain_parity(
+                        (off, cols, parity), parities, timer, crcs, k
+                    ),
+                    nbytes=getattr(parity, "nbytes", 0),
+                )
+
+            # Stream segments through the device, staging on a worker
+            # thread (SegmentPrefetcher) so read IO overlaps the drains'
+            # writes — the three-way overlap of the reference's stream
+            # loop (encode.cu:165-218).
             with SegmentPrefetcher(
                 _segment_spans(chunk, seg_cols), gather_segment,
                 depth=pipeline_depth,
-            ) as prefetch, AsyncWindow(
-                pipeline_depth,
-                lambda tag, fut: _drain_parity(
-                    (*tag, fut), parity_files, timer, crcs, k
-                ),
-                executor=dex,
-            ) as window:
+            ) as prefetch, AsyncWindow(pipeline_depth, drain) as window:
                 # 5-stage pipeline: the prefetcher reads segment i+2, the
                 # ring issues segment i+1's H2D (an async device_put of the
                 # bucket-padded segment, see plan.py) while segment i
                 # computes, and the write-behind executor drains segment
-                # i-1's D2H + parity writes off the dispatch thread.
-                # Ordered lane: the incremental parity CRC (and the
-                # no-toolchain seek/write fallback) need commits in column
-                # order.
+                # i-1's native rows, D2H and parity off the dispatch thread.
+                # Ordered lane: the incremental CRCs (and the no-toolchain
+                # seek/write fallback) need commits in column order.  The
+                # ring's tag carries each host segment to its drain.
                 staging = _staging_ring(
-                    prefetch, codec, seg_cols, sym, pipeline_depth
+                    (((off, cols, seg), seg) for (off, cols), seg in prefetch),
+                    codec, seg_cols, sym, pipeline_depth,
                 )
-                for (off, cols), seg in staging:
+                for (off, cols, host), seg in staging:
                     with timer.phase("encode dispatch"), _dispatch_span(
                         "encode", off, cols
                     ):
                         parity = codec.encode(seg)  # async
-                    window.push((off, cols), parity)
+                    window.push((off, cols), (host, parity))
         if _fleet is not None:
             _fleet.commit(key, finalize)
         else:
@@ -787,6 +779,23 @@ def encode_file(
             cleanup()
         raise
     return written
+
+
+def _drain_natives(off, seg, native_files, timer, crcs=None) -> None:
+    """Write a row-layout encode segment's k native rows from the gathered
+    host segment ``seg`` at chunk offset ``off``, with their incremental
+    CRC32 (see :func:`_drain_parity` for the commit rule)."""
+    from . import native
+
+    with timer.phase("write natives (io)"):
+        native.scatter_write(native_files, seg, off, call="native_rows")
+    if crcs is not None:
+        with _obs_tracing.span("crc", lane="crc"):
+            new_crcs = {
+                i: crc32_of(seg[i], crcs.get(i, 0))
+                for i in range(seg.shape[0])
+            }
+        crcs.update(new_crcs)
 
 
 def _drain_parity(entry, parity_files, timer, crcs=None, k=0) -> None:
@@ -897,7 +906,10 @@ def _encode_file_interleaved(
             )
             if crcs is not None:
                 append_checksums(commit.meta_tmp, crcs)
-        commit.promote()
+        # Renames over a previous archive's chunks can wait on the disk's
+        # writeback; the phase names that wait.
+        with timer.phase("promote chunks (io)"):
+            commit.promote()
 
     def cleanup() -> None:
         for fp in files:

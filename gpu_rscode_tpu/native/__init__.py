@@ -221,9 +221,13 @@ def stripe_read(
     return dst
 
 
-def scatter_write(files, arr: np.ndarray, off: int) -> None:
+def scatter_write(
+    files, arr: np.ndarray, off: int, call: str = "scatter_write"
+) -> None:
     """Write each row of (p, cols) ``arr`` to the matching open binary file
-    at byte offset ``off`` (native pwrite; Python seek/write fallback)."""
+    at byte offset ``off`` (native pwrite; Python seek/write fallback).
+    ``call`` labels the bytes in ``rs_io_write_*_total``: ``native_rows``
+    for a row-layout encode's native rows, else ``scatter_write``."""
     arr = np.ascontiguousarray(arr, dtype=np.uint8)
     p, cols = arr.shape
     assert len(files) == p
@@ -234,16 +238,14 @@ def scatter_write(files, arr: np.ndarray, off: int) -> None:
         for fp, row in zip(files, arr):
             fp.seek(off)
             fp.write(row.tobytes())
-        _count_io(
-            "write", "scatter_write", arr.nbytes, time.perf_counter() - t0
-        )
+        _count_io("write", call, arr.nbytes, time.perf_counter() - t0)
         return
     for fp in files:
         fp.flush()  # nothing buffered may straddle the raw pwrite below
     fds = (ctypes.c_int * p)(*[fp.fileno() for fp in files])
     if lib.rs_scatter_write(fds, arr, p, cols, off) != 0:
         raise OSError("rs_scatter_write failed (short write)")
-    _count_io("write", "scatter_write", arr.nbytes, time.perf_counter() - t0)
+    _count_io("write", call, arr.nbytes, time.perf_counter() - t0)
 
 
 def gather_rows(files, off: int, cols: int, fallback_maps=None) -> np.ndarray:

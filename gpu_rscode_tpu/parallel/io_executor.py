@@ -169,10 +169,10 @@ class DrainExecutor:
         # plane's write hook fires per attempt (injected ioerror/torn/
         # delay), and transient failures — injected or real — retry the
         # whole drain under the default policy.  Drains are idempotent by
-        # construction: offset-addressed pwrites, restart-from-scratch
-        # copies, and incremental-CRC commits deferred until after the
-        # write landed.  The lane's attempted-byte accounting (torn
-        # faults) counts a task's bytes once, not per retry.
+        # construction: offset-addressed pwrites and incremental-CRC
+        # commits deferred until after the writes landed.  The lane's
+        # attempted-byte accounting (torn faults) counts a task's bytes
+        # once, not per retry.
         first = True
 
         def attempt() -> None:

@@ -250,6 +250,98 @@ def test_checksum_segmented_encode_consistent(tmp_path):
         assert crcs[i] == whole, f"chunk {i}"
 
 
+# ----- native chunks written from the gathered segments ---------------------
+
+
+def _check_archive(path, data, k, p, chunk, checksums):
+    """The k natives are the plain file ranges, tail zero-padded; the CRC
+    lines (if any) are the files' own; the parity decodes the data back
+    with the first k chunks lost."""
+    import zlib
+
+    from gpu_rscode_tpu.utils.fileformat import (
+        metadata_file_name,
+        read_checksums,
+    )
+
+    files = [open(chunk_file_name(path, i), "rb").read() for i in range(k + p)]
+    plain = [data[i * chunk:(i + 1) * chunk].ljust(chunk, b"\0")
+             for i in range(k)]
+    assert files[:k] == plain
+    want = {i: zlib.crc32(f) for i, f in enumerate(files)} if checksums else {}
+    assert read_checksums(metadata_file_name(path)) == want
+    conf = make_conf(k + p, k, path, survivors=list(range(p, k + p)))
+    out = path + ".out"
+    api.decode_file(path, conf, out)
+    assert open(out, "rb").read() == data
+
+
+@pytest.mark.parametrize("checksums", [True, False])
+@pytest.mark.parametrize("w", [8, 16])
+def test_natives_leave_from_the_gathered_segments(tmp_path, w, checksums):
+    """Each segment's drain writes the segment's native rows from the
+    gathered host segment: over 13 segments and a zero-padded tail the
+    archive is exact, and rs_io_write_bytes_total{call="native_rows"}
+    counts every native byte once."""
+    from gpu_rscode_tpu.obs import metrics
+
+    k, p, size = 4, 2, 50_001
+    path = _mkfile(tmp_path, size, seed=40 + w)
+    data = open(path, "rb").read()
+    chunk = chunk_size_for(size, k, w // 8)
+    assert chunk * k > size and chunk // 1024 >= 12  # tail pad, 13 segments
+    metrics.REGISTRY.reset()
+    metrics.force_enable()
+    try:
+        api.encode_file(path, k, p, w=w, checksums=checksums,
+                        segment_bytes=4096)
+        counted = metrics.REGISTRY.snapshot()["rs_io_write_bytes_total"]
+    finally:
+        metrics.force_enable(False)
+        metrics.REGISTRY.reset()
+    assert counted["values"]['{call="native_rows"}'] == k * chunk
+    _check_archive(path, data, k, p, chunk, checksums)
+
+
+@pytest.mark.parametrize("fault", ["plane", "mid_drain"])
+def test_native_crcs_commit_once_when_a_drain_retries(tmp_path, monkeypatch,
+                                                      fault):
+    """A transient write fault retries the whole drain task; each native
+    CRC is still committed once, so the CRC lines are those of the files.
+    ``plane``: RS_FAULTS fails drains before they write; ``mid_drain``:
+    the first native and the first parity write of each segment fail
+    inside their drains."""
+    import errno
+
+    from gpu_rscode_tpu import native
+    from gpu_rscode_tpu.resilience import faults
+
+    monkeypatch.setenv("RS_RETRY_BASE_MS", "1")
+    monkeypatch.setenv("RS_RETRY_ATTEMPTS", "10")
+    real = native.scatter_write
+    failed = []
+
+    def flaky(files, arr, off, **kw):
+        if fault == "mid_drain" and (off, len(files)) not in failed:
+            failed.append((off, len(files)))
+            raise OSError(errno.EIO, "transient write")
+        return real(files, arr, off, **kw)
+
+    monkeypatch.setattr(native, "scatter_write", flaky)
+    k, p, size = 4, 2, 50_001
+    path = _mkfile(tmp_path, size, seed=45)
+    data = open(path, "rb").read()
+    plan = (faults.parse_plan("write:ioerror@p=0.3", seed=7)
+            if fault == "plane" else None)
+    with faults.activate(plan):
+        api.encode_file(path, k, p, checksums=True, segment_bytes=4096)
+    if fault == "plane":
+        assert plan.injected[("ioerror", "write")] > 0
+    else:
+        assert len(failed) == 2 * 13
+    _check_archive(path, data, k, p, chunk_size_for(size, k), True)
+
+
 # ----- wide-symbol (GF(2^16)) file coding -----------------------------------
 
 

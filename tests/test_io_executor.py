@@ -290,7 +290,7 @@ def test_sync_and_writebehind_chunks_identical(tmp_path, monkeypatch):
 
 
 def test_slow_writer_backpressure_still_correct(tmp_path, monkeypatch):
-    """An induced slow writer (every parity write sleeps) forces the
+    """An induced slow writer (every row-scatter write sleeps) forces the
     dispatch loop into backpressure; bytes must still be correct."""
     monkeypatch.setenv("RS_IO_WRITERS", "1")
     monkeypatch.setenv("RS_IO_WRITE_DEPTH", "1")
@@ -298,9 +298,9 @@ def test_slow_writer_backpressure_still_correct(tmp_path, monkeypatch):
 
     real = native.scatter_write
 
-    def slow_scatter(files, arr, off):
+    def slow_scatter(files, arr, off, **kw):
         time.sleep(0.05)
-        return real(files, arr, off)
+        return real(files, arr, off, **kw)
 
     monkeypatch.setattr(native, "scatter_write", slow_scatter)
     path, data = _make_file(tmp_path, size=200_000)
@@ -322,11 +322,11 @@ def test_writer_exception_fails_encode_atomically(tmp_path, monkeypatch):
     calls = []
     real = native.scatter_write
 
-    def failing_scatter(files, arr, off):
+    def failing_scatter(files, arr, off, **kw):
         calls.append(1)
         if len(calls) >= 2:
             raise OSError("disk gone (writer lane)")
-        return real(files, arr, off)
+        return real(files, arr, off, **kw)
 
     monkeypatch.setattr(native, "scatter_write", failing_scatter)
     path, _ = _make_file(tmp_path)

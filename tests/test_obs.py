@@ -288,10 +288,12 @@ def test_file_path_spans_reach_the_profiler(tmp_path, clean_registry,
     for once in ("rs.encode", "rs.repair", "rs.scan_chunks"):
         assert names.count(once) == 1, once
     assert names.count("rs.dispatch") == 6  # 3 segments, each op
-    # k native copies, 3 parity drains, 3 repair drains
-    assert names.count("rs.crc") == k + 3 + 3
-    assert names.count("rs.write_drain") >= k + 3 + 3
-    assert "rs.wait_writer" in names and "rs.write natives (io)" in names
+    # Per segment, an encode drains its native rows and then its parity,
+    # each with a CRC span; a repair drains its rebuilt row.
+    assert names.count("rs.write_drain") == 2 * 3 + 3
+    assert names.count("rs.write natives (io)") == 3
+    assert names.count("rs.crc") == 2 * 3 + 3
+    assert "rs.wait_writer" in names
     waited = sum(d for n, d in spans if n.startswith("rs.wait_"))
     snap = metrics.REGISTRY.snapshot()
     counted = snap["rs_pipeline_wait_seconds_total"]["values"]
@@ -455,6 +457,7 @@ def test_existing_phase_names_classify_exactly():
         "write natives (io)", "stage segment (io)", "write parity (io)",
         "write metadata (io)", "read metadata (io)", "open chunks (io)",
         "write output (io)", "scan chunks (io)", "write chunks (io)",
+        "promote chunks (io)",
     ]
     comp = [
         "encode dispatch", "encode compute", "decode dispatch",

@@ -111,3 +111,48 @@ def test_mesh_overhead_smoke():
     ratios = [d for d in got if "overhead_vs_single" in d]
     assert {d["mode"] for d in ratios} == {"cols", "stripe"}
 
+
+
+def test_encode_split_smoke():
+    """Per-op rows of the encode split: two lane tasks a segment (native
+    rows, then parity), the lane's parts adding up to its busy time, and
+    the renames timed; the disk probe's rows before them."""
+    got = _run_tool(
+        "gpu_rscode_tpu.tools.encode_split", "--bytes", "600001",
+        "--ops", "2", "--disk", "1", "--k", "4", "--p", "2",
+    )
+    parts = [d["part"] for d in got]
+    assert parts == ["disk", "setup", "encode", "encode"]
+    for row in got[2:]:
+        assert row["tasks"] == 2  # one 150,001-column segment
+        lane = sum(row[k] for k in ("crc_s", "natives_s", "parity_s",
+                                    "d2h_s", "task_other_s"))
+        assert lane == pytest.approx(row["lane_s"], abs=1e-5)
+        assert row["crc_s"] > 0 and row["natives_s"] > 0
+        assert 0 < row["promote_s"] <= row["tail_s"] <= row["op_s"]
+
+
+def test_encode_split_counts_each_lane_second_once():
+    """A ``crc`` span inside a native copy (as the lane's tasks once
+    nested them) counts as CRC, not twice; time outside the lane's tasks
+    counts in no part of it."""
+    from gpu_rscode_tpu.tools.encode_split import split
+
+    def x(name, ts, dur):
+        return {"ph": "X", "name": name, "ts": ts * 1e6, "dur": dur * 1e6}
+
+    row = split([
+        x("encode", 0.0, 10.0),
+        x("write_drain", 1.0, 4.0), x("write natives (io)", 1.0, 3.0),
+        x("crc", 2.0, 1.0),
+        x("write_drain", 6.0, 2.0), x("encode compute", 6.0, 0.5),
+        x("write parity (io)", 6.5, 1.0),
+        x("stage segment (io)", 0.0, 2.0), x("promote chunks (io)", 8.5, 1.0),
+    ])
+    assert row == {
+        "op_s": 10.0, "tasks": 2, "lane_s": 6.0, "head_s": 1.0,
+        "tail_s": 2.0, "lane_gap_s": 1.0, "crc_s": 1.0, "natives_s": 2.0,
+        "parity_s": 1.0, "d2h_s": 0.5, "task_other_s": 1.5,
+        "promote_s": 1.0, "gather_s": 2.0, "wait_writer_s": 0,
+        "wait_segment_s": 0,
+    }
