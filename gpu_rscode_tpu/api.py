@@ -33,6 +33,7 @@ from collections import OrderedDict
 from contextlib import contextmanager, nullcontext
 
 from .codec import RSCodec
+from .models.lrc import erasure_tolerance, local_plans
 from .obs import attrib as _obs_attrib, health as _obs_health, \
     metrics as _obs_metrics, runlog as _obs_runlog, tracing as _obs_tracing
 from .parallel.io_executor import DrainExecutor, FleetPipeline
@@ -2198,9 +2199,11 @@ def _scan_chunks(in_file: str, segment_bytes: int) -> _ChunkScan:
             "rs_scrub_archives_scanned_total", "archive health scans"
         ).labels(outcome="damaged" if bad or len(healthy) < k + p
                  else "clean").inc()
+        tolerance = erasure_tolerance(total_mat, k, w)
         _obs_health.record_damage(
             "scan", in_file, states=damage_states, k=k, p=p, w=w,
             generation=meta.generation,
+            tolerance=None if tolerance == p else tolerance,
         )
         _obs_metrics.counter(
             "rs_scrub_scan_seconds_total",
@@ -3009,6 +3012,11 @@ def repair_file(
     when checksums are present.  Raises ValueError when fewer than k
     healthy chunks remain.
 
+    Survivors come from the repair planner (:func:`_plan_repair`): where
+    the total matrix has local groups (an LRC), a lost chunk whose group
+    is otherwise healthy is rebuilt from that group alone; otherwise from
+    k survivors whose submatrix inverts, as for any MDS code.
+
     With a ``mesh`` the rebuild GEMM fans out across devices exactly like
     encode/decode (archive repair is the same bulk-data shape — the
     reference runs its decode multi-GPU, decode.cu:335-378);
@@ -3042,9 +3050,9 @@ def repair_file(
             )
         return _count_repair_outcome(targets, in_file, scan)
     with timer.phase("invert matrix"):
-        chosen, inv = _repair_select_or_fail(scan)
+        chosen, rebuild_mat = _repair_select_or_fail(scan)
     return _count_repair_outcome(_repair_streamed(
-        in_file, scan, chosen, inv, strategy=strategy,
+        in_file, scan, chosen, rebuild_mat, strategy=strategy,
         segment_bytes=segment_bytes, pipeline_depth=pipeline_depth,
         mesh=mesh, stripe_sharded=stripe_sharded, timer=timer,
     ), in_file, scan)
@@ -3076,13 +3084,63 @@ def _count_repair_outcome(rebuilt: list[int], in_file: str | None = None,
     return rebuilt
 
 
+def _plan_repair(scan: "_ChunkScan") -> tuple[list[int], np.ndarray]:
+    """The survivors a repair reads and the (targets x survivors) matrix
+    that rebuilds every unhealthy chunk from them.
+
+    Local plan: each target has a local group in the total matrix
+    (:func:`.models.lrc.local_plans`, from the supports of its parity
+    rows) whose other members are all healthy, and together the groups
+    read fewer than k chunks: the union of the groups, each target's row
+    holding its own group's coefficients.  Otherwise the global plan: k
+    healthy chunks whose submatrix inverts
+    (:func:`_select_decodable_subset`, subset cache included) and the
+    rebuild rows ``T[targets] . inv``.  An MDS matrix has no local
+    groups, so for it this is exactly the subset and matrix repair has
+    always used.
+    """
+    from .ops.gf import get_field
+
+    targets = scan.unhealthy
+    with _obs_tracing.span("repair_plan", file=scan.in_file,
+                           targets=len(targets)):
+        plans = local_plans(scan.total_mat, scan.k, scan.w)
+        local = [plans.get(t) for t in targets]
+        chosen: list[int] = []
+        if all(pl is not None and set(pl[0]) <= set(scan.healthy)
+               for pl in local):
+            chosen = sorted(set().union(*(pl[0] for pl in local)))
+        if not chosen or len(chosen) >= scan.k:
+            return _global_plan(scan, *_select_decodable_subset(scan))
+        rebuild_mat = np.zeros((len(targets), len(chosen)),
+                               get_field(scan.w).dtype)
+        col = {c: j for j, c in enumerate(chosen)}
+        for r, (survivors, coef) in enumerate(local):
+            rebuild_mat[r, [col[c] for c in survivors]] = coef
+    return chosen, rebuild_mat
+
+
+def _global_plan(scan: "_ChunkScan", chosen, inv):
+    """The global repair plan from k survivors and the inverse of their
+    submatrix: ``(chosen, T[targets] . inv)``."""
+    from .ops.gf import get_field
+
+    gf = get_field(scan.w)
+    inv = np.asarray(inv).astype(gf.dtype)
+    rebuild_mat = gf.matmul(
+        scan.total_mat.astype(gf.dtype)[scan.unhealthy], inv
+    )  # (targets, k)
+    return list(chosen), rebuild_mat
+
+
 def _repair_select_or_fail(scan: "_ChunkScan"):
-    """Survivor-subset selection for a single-archive repair, recording
-    the failure to the health plane before it propagates: an archive
-    repair cannot fix is the strongest at-risk signal the fleet model
-    has (the repair-failure term in docs/HEALTH.md's risk formula)."""
+    """The repair plan of a single-archive repair (:func:`_plan_repair`),
+    recording the failure to the health plane before it propagates: an
+    archive repair cannot fix is the strongest at-risk signal the fleet
+    model has (the repair-failure term in docs/HEALTH.md's risk
+    formula)."""
     try:
-        return _select_decodable_subset(scan)
+        return _plan_repair(scan)
     except ValueError as e:
         _obs_health.record_damage(
             "repair_failed", scan.in_file,
@@ -3097,7 +3155,7 @@ def _repair_streamed(
     in_file: str,
     scan: "_ChunkScan",
     chosen: list[int],
-    inv: np.ndarray,
+    rebuild_mat: np.ndarray,
     *,
     strategy: str,
     segment_bytes: int,
@@ -3108,28 +3166,32 @@ def _repair_streamed(
     fleet: FleetPipeline | None = None,
 ) -> list[int]:
     """The streaming rebuild half of :func:`repair_file`: given a completed
-    scan and a chosen survivor subset with its inverse, regenerate every
-    unhealthy chunk.  Split out so :func:`repair_fleet` can supply inverses
-    computed in one batched on-device dispatch — and, with ``fleet``, ride
-    the fleet's shared write-behind lane: this archive's promote/checksum
-    commit queues behind its writes while the caller already streams the
-    next archive's reads and dispatches."""
-    from .ops.gf import get_field
-
+    scan, the survivors ``chosen`` and the (targets x survivors)
+    ``rebuild_mat`` of a repair plan, regenerate every unhealthy chunk
+    reading only ``chosen``.  Split out so :func:`repair_fleet` can supply
+    inverses computed in one batched on-device dispatch — and, with
+    ``fleet``, ride the fleet's shared write-behind lane: this archive's
+    promote/checksum commit queues behind its writes while the caller
+    already streams the next archive's reads and dispatches."""
     targets = scan.unhealthy
-    with timer.phase("rebuild matrix"):
-        gf = get_field(scan.w)
-        mat = scan.total_mat.astype(gf.dtype)
-        inv = np.asarray(inv).astype(gf.dtype)
-        rebuild_mat = gf.matmul(mat[targets], inv)  # (targets, k)
-
     codec = RSCodec(
         scan.k, scan.p, w=scan.w, strategy=strategy,
         mesh=mesh, stripe_sharded=stripe_sharded,
     )
     sym = scan.w // 8
     chunk = scan.chunk
-    seg_cols = _segment_cols(chunk, scan.k, segment_bytes)
+    seg_cols = _segment_cols(chunk, len(chosen), segment_bytes)
+    # A plan that reads fewer than k survivors is a local one.
+    _obs_metrics.counter(
+        "rs_repair_plans_total",
+        "chunks rebuilt by plan: local (< k survivors) or global (k)",
+    ).labels(
+        plan="local" if len(chosen) < scan.k else "global"
+    ).inc(len(targets))
+    _obs_metrics.counter(
+        "rs_repair_survivors_read_total",
+        "survivor chunk files read by streamed rebuilds",
+    ).inc(len(chosen))
 
     from . import native
 
@@ -3487,9 +3549,18 @@ def repair_fleet(
     # _select_decodable_subset would try first (the near-always-invertible
     # common case for Vandermonde/Cauchy).
     chosen_inv: dict[str, tuple[list[int], np.ndarray]] = {}
+    plans: dict[str, tuple[list[int], np.ndarray]] = {}
     groups: dict[tuple[int, int], list[str]] = {}
     for f, s in scans.items():
         if not s.unhealthy:
+            continue
+        if s.chunk and local_plans(s.total_mat, s.k, s.w):
+            # A code with local groups (LRC): the planner reads a group
+            # where it can, and inverts its own k x k where it cannot.
+            try:
+                plans[f] = _plan_repair(s)
+            except ValueError as e:
+                errors[f] = str(e)
             continue
         if s.chunk == 0:
             # Zero-size archives skip inversion but NOT validation: an
@@ -3582,6 +3653,8 @@ def repair_fleet(
             "unrecoverable archives (nothing repaired): "
             + "; ".join(f"{f}: {msg}" for f, msg in sorted(errors.items()))
         )
+    for f, (chosen, inv) in chosen_inv.items():
+        plans[f] = _global_plan(scans[f], chosen, inv)
     # Fleet scheduler: one shared ordered write-behind lane; each archive
     # commits behind its own writes while the next archive's reads and
     # dispatches already stream on this thread.
@@ -3599,9 +3672,9 @@ def repair_fleet(
                     pipeline_depth=pipeline_depth, timer=timer,
                 )
             else:
-                chosen, inv = chosen_inv[f]
+                chosen, rebuild_mat = plans[f]
                 results[f] = _count_repair_outcome(_repair_streamed(
-                    f, s, chosen, inv, strategy=strategy,
+                    f, s, chosen, rebuild_mat, strategy=strategy,
                     segment_bytes=segment_bytes,
                     pipeline_depth=pipeline_depth,
                     mesh=None, stripe_sharded=False, timer=timer,

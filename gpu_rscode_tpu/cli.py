@@ -11,7 +11,7 @@ rejected unless a decode was selected first, matching the reference's
 ordering rule.
 
 Extensions (flagged long options, no reference equivalent):
-``--generator {vandermonde,cauchy}``,
+``--generator {vandermonde,cauchy,lrc-12-2-2}`` (docs/LRC.md),
 ``--strategy {auto,bitplane,table,pallas,xor,ring,cpu}`` (default auto,
 resolved per backend by the strategy autotuner: the fused pallas kernel
 on TPU hardware, meshes included — every fused dispatch is guarded with
@@ -45,7 +45,7 @@ Performance-tuning options:
          kernel's internal column tile comes from committed sweeps and is
          overridable via env RS_PALLAS_TILE
 [-s|-S]: pipeline depth (segments in flight, default 2)
-Extensions: [--generator vandermonde|cauchy]
+Extensions: [--generator vandermonde|cauchy|lrc-12-2-2]
             [--strategy auto|bitplane|table|pallas|xor|ring|cpu]
             (default auto: resolved by the per-backend strategy
             autotuner — pallas kernel on TPU incl. meshes, bitplane

@@ -18,6 +18,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..ops.gf import GaloisField, get_field
+from .lrc import azure_lrc_12_2_2
 
 
 def vandermonde_matrix(parity_num: int, native_num: int, gf: GaloisField | None = None) -> np.ndarray:
@@ -60,9 +61,12 @@ def cauchy_matrix(parity_num: int, native_num: int, gf: GaloisField | None = Non
     return gf.inv(x[:, None] ^ y[None, :]).astype(gf.dtype)
 
 
+# Name -> (p, k) parity block.  A non-MDS code fixes its own structure in
+# its name (``lrc-12-2-2``: k=12 in two local groups, two globals).
 GENERATORS = {
     "vandermonde": vandermonde_matrix,
     "cauchy": cauchy_matrix,
+    "lrc-12-2-2": azure_lrc_12_2_2,
 }
 
 

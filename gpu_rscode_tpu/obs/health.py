@@ -133,6 +133,7 @@ def record_damage(
     w: int | None = None,
     generation: int | None = None,
     verdict: str | None = None,
+    tolerance: int | None = None,
     ledger_path: str | None = None,
 ) -> None:
     """Append one ``rs_damage`` event record to the run ledger.
@@ -142,7 +143,9 @@ def record_damage(
     damage.  ``states`` maps chunk index -> damage state (a ``scan``
     event's full verdict: an EMPTY map is meaningful, it clears prior
     damage); ``chunks`` is a bare index list (syndrome attributions,
-    rebuilt chunks).
+    rebuilt chunks).  ``tolerance`` is the losses the archive's code
+    survives whatever chunks they hit, given only where it is not p (a
+    non-MDS code such as an LRC).
     """
     try:
         if ledger_path is None and not _runlog.enabled():
@@ -161,7 +164,8 @@ def record_damage(
                 for i, s in sorted(states.items(), key=lambda kv: int(kv[0]))
             }
         for name, v in (("k", k), ("p", p), ("w", w),
-                        ("generation", generation)):
+                        ("generation", generation),
+                        ("tolerance", tolerance)):
             if v is not None:
                 fields[name] = int(v)
         if verdict is not None:
@@ -295,7 +299,9 @@ def _apply_event(state: dict, rec: dict) -> None:
         ts = float(rec.get("ts") or 0.0)
     except (TypeError, ValueError):
         ts = 0.0
-    for f in ("k", "p", "w"):
+    # "tolerance" exists only for archives whose records carry it (non-MDS
+    # codes), so canonical() is unchanged for MDS fleets.
+    for f in ("k", "p", "w", "tolerance"):
         v = rec.get(f)
         if isinstance(v, int) and not isinstance(v, bool):
             a[f] = v
@@ -507,18 +513,23 @@ def load(ledger_path: str | None = None,
 def risk(a: dict, now: float | None = None) -> dict:
     """Score one archive's distance to data loss.
 
-    ``margin = p - lost`` is the erasures the stripe can still absorb
-    (``n - k - lost``); the base term ``min(1, lost/(p+1))`` saturates at
-    1.0 exactly when the stripe is past recovery.  Modifiers (bitrot
+    ``margin = t - lost`` is the erasures the stripe is still guaranteed
+    to absorb, ``t`` the code's tolerance: ``p`` (``n - k``) for an MDS
+    code, less for a locally repairable one (3 for LRC(12,2,2), whose
+    p is 4), as the archive's damage records give it.  The base term
+    ``min(1, lost/(t+1))`` saturates at 1.0 exactly when a loss pattern
+    past recovery is possible.  Modifiers (bitrot
     recurrence, scrub staleness, repair-failure history) add at most 0.5,
     so they reorder archives WITHIN a margin class but never above one.
     """
     now = time.time() if now is None else float(now)
     p = a.get("p")
     p = p if isinstance(p, int) and not isinstance(p, bool) and p >= 0 else 0
+    t = a.get("tolerance", p)
+    t = t if isinstance(t, int) and not isinstance(t, bool) and t >= 0 else p
     lost = len(a.get("chunks") or {})
-    margin = p - lost
-    base = min(1.0, lost / float(p + 1))
+    margin = t - lost
+    base = min(1.0, lost / float(t + 1))
     rot = min(1.0, (a.get("bitrot_events") or 0) / 4.0)
     fails = min(1.0, (a.get("repair_failures") or 0) / 2.0)
     tau = scrub_max_age_s()

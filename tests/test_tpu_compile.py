@@ -63,6 +63,10 @@ def _spec(shape, w, sharding):
         (4, 2, 8, 1 * MiB, None),       # small k, 1 MiB cells
         (10, 4, 16, 16 * MiB, "sum"),   # wide symbols
         (128, 16, 8, 1 * MiB, None),    # BASELINE's wide stripe
+        # LRC(12,2,2) repair of an 89.5 MB fragment, one 64 MiB segment:
+        (6, 1, 8, 11184768, None),      # a local group's 1 x 6 rebuild
+        (12, 1, 8, 5592320, None),      # a global parity's 1 x 12
+        (12, 4, 8, 5592320, None),      # its encode
     ],
 )
 def test_single_chip_kernel_compiles(topo, on_tpu, k, p, w, chunk, refold):
